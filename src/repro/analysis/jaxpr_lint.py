@@ -43,11 +43,8 @@ HOST_CALLBACK_PRIMITIVES = frozenset(
 COLLECTIVE_PRIMITIVES = frozenset(
     {
         "psum",
-        "psum2",  # shard_map-era spelling in jax 0.4.x
         "pmin",
-        "pmin2",
         "pmax",
-        "pmax2",
         "pmean",
         "all_gather",
         "all_to_all",

@@ -266,6 +266,24 @@ class GraphStream:
         else:
             self._window = None
             self._sketch = GLavaSketch.empty(config, jax.random.key(seed))
+        if mesh is not None:
+            # On a mesh, `auto` picks the paths that run on row-sharded
+            # counters: the compiler cannot partition a Mosaic kernel
+            # (queries), and the ingest kernel's outputs carry no shard_map
+            # varying-axes type yet.
+            if ingest_backend in (None, "auto"):
+                ingest_backend = "onehot"
+            if query_backend in (None, "auto"):
+                query_backend = "jnp"
+            # The estimators then run on the sharded counters as on one
+            # device: Auto axes let the compiler partition them, where
+            # Explicit axes (jax.make_mesh's default) would demand an output
+            # sharding for every gather.
+            mesh = jax.sharding.Mesh(
+                mesh.devices,
+                mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * mesh.devices.ndim,
+            )
         # "fused" is a session-level mode, not an IngestEngine backend: the
         # one-pass kernel updates counters + registers + touched bitmap
         # together, which only a plain local session can consume.
@@ -690,17 +708,20 @@ class GraphStream:
 
             self.flush()
             if pre is not None:
+                # Bucket padding (zero weights are the identity) bounds the
+                # shapes the sharded ingest compiles for, as on one device.
                 self._sketch = distributed_ingest(
                     self._mesh,
                     self._sketch,
-                    jnp.asarray(pre.src),
-                    jnp.asarray(pre.dst),
-                    jnp.asarray(pre.weights),
+                    jnp.asarray(pad_bucket(pre.src)),
+                    jnp.asarray(pad_bucket(pre.dst)),
+                    jnp.asarray(pad_bucket(pre.weights)),
+                    backend=self.ingest_backend,
                     preagg_marginals=(
-                        jnp.asarray(pre.src_unique),
-                        jnp.asarray(pre.src_totals),
-                        jnp.asarray(pre.dst_unique),
-                        jnp.asarray(pre.dst_totals),
+                        jnp.asarray(pad_bucket(pre.src_unique)),
+                        jnp.asarray(pad_bucket(pre.src_totals)),
+                        jnp.asarray(pad_bucket(pre.dst_unique)),
+                        jnp.asarray(pad_bucket(pre.dst_totals)),
                     ),
                 )
             else:
@@ -710,6 +731,7 @@ class GraphStream:
                     jnp.asarray(s_np),
                     jnp.asarray(d_np),
                     jnp.asarray(w_np),
+                    backend=self.ingest_backend,
                 )
             self._inflight.append(self._sketch.counters)
         elif pre is not None and not self._fused:

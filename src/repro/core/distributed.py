@@ -23,11 +23,11 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.ingest import ingest
 from repro.core.sketch import GLavaSketch, scatter_flows, scatter_register
-from repro.distributed.compat import shard_map
 
 
 def distributed_ingest(
@@ -70,10 +70,13 @@ def distributed_ingest(
 
     def body(counters_shard, r, c, w):
         row_lo = jax.lax.axis_index(model_axis) * wr_shard
-        upd = ingest(counters_shard, r, c, w, backend=backend, row_offset=row_lo)
+        # The counters arrive replicated over the stream axes; each stream
+        # shard's partial update varies over them, so the ingest starts
+        # from a varying copy.
+        local = jax.lax.pcast(counters_shard, tuple(stream_axes), to="varying")
+        upd = ingest(local, r, c, w, backend=backend, row_offset=row_lo)
         # Merge stream shards: the paper's distributed merge-by-add.
-        delta = upd - counters_shard
-        delta = jax.lax.psum(delta, tuple(stream_axes))
+        delta = jax.lax.psum(upd - local, tuple(stream_axes))
         return counters_shard + delta
 
     counters = shard_map(
@@ -100,8 +103,13 @@ def distributed_ingest(
             sketch.col_flows, sketch.col_hash(dst_unique), dst_totals
         )
     else:
+        # The registers are replicated, so the batch is too: a flat scatter
+        # index cannot be reshaped out of an axis sharded over the stream.
+        replicated = NamedSharding(mesh, P())
         row_flows, col_flows = scatter_flows(
-            sketch.row_flows, sketch.col_flows, r, c, weights
+            sketch.row_flows,
+            sketch.col_flows,
+            *(jax.sharding.reshard(x, replicated) for x in (r, c, weights)),
         )
     return dataclasses.replace(
         sketch, counters=counters, row_flows=row_flows, col_flows=col_flows

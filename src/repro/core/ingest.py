@@ -31,9 +31,9 @@ Ingest-backend selection
              ``M += OneHot(r)^T @ (OneHot(c) * w)`` — a systolic matmul.
              Best for XLA:TPU without Pallas.
 ``pallas``   The Pallas TPU kernel implementing the one-hot formulation
-             with explicit VMEM tiling (``repro.kernels.ingest``).  The
-             fast path on TPU hardware; on CPU hosts it runs in interpret
-             mode (a correctness artifact, not a perf claim).
+             with explicit VMEM tiling (``repro.kernels.ingest``).  Compiled
+             on TPU hardware; on CPU hosts it runs in interpret mode (a
+             correctness artifact, not a perf claim).
 ``auto``     Resolves via the ``REPRO_INGEST_BACKEND`` environment
              variable if set, else ``pallas`` on TPU backends and
              ``scatter`` elsewhere.
@@ -142,7 +142,12 @@ def _onehot(counters, local_r, cols, weights, in_shard, chunk):
         oh_r = jax.nn.one_hot(rc, wr + 1, dtype=jnp.float32)[..., :wr]  # (d, C, wr)
         oh_c = jax.nn.one_hot(cc, wc, dtype=jnp.float32)                # (d, C, wc)
         oh_c = oh_c * wchunk[None, :, None]
-        return counters + jnp.einsum("dbr,dbc->drc", oh_r, oh_c), None
+        # HIGHEST: a TPU's default f32 matmul rounds operands to bf16, which
+        # would round weighted one-hots and break the exactness contract.
+        upd = jnp.einsum(
+            "dbr,dbc->drc", oh_r, oh_c, precision=jax.lax.Precision.HIGHEST
+        )
+        return counters + upd, None
 
     n = r_sent.shape[1] // chunk
     rs = r_sent.reshape(d, n, chunk).transpose(1, 0, 2)
@@ -162,7 +167,7 @@ def _pallas(counters, local_r, cols, weights, in_shard, chunk):
     rp = pad_to(r, CHUNK_B, 1, value=-1)
     cl = pad_to(cols.astype(jnp.int32), CHUNK_B, 1)
     wp = pad_to(weights, CHUNK_B, 0)  # padded edges carry weight 0
-    out = ingest_pallas(cp, rp, cl, wp, interpret=jax.default_backend() != "tpu")
+    out = ingest_pallas(cp, rp, cl, wp)
     return out[:, :wr, :wc]
 
 

@@ -70,9 +70,7 @@ def resolve_query_backend(backend: Optional[str]) -> str:
 def _pallas_edge_query(sketch: GLavaSketch, src: jax.Array, dst: jax.Array):
     from repro.kernels.query import ops as query_ops
 
-    est = query_ops.edge_query(
-        sketch, src, dst, interpret=jax.default_backend() != "tpu"
-    )
+    est = query_ops.edge_query(sketch, src, dst)
     # The kernel computes in fp32; counter values are exact integers there
     # (counting regime), so the cast back to the counter dtype is lossless
     # and keeps both backends dtype-identical.

@@ -18,9 +18,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.distributed.compat import shard_map
 
 
 def pipeline_apply(

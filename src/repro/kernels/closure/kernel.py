@@ -9,10 +9,13 @@ adds on the MXU.  ops.py iterates ceil(log2(w)) squarings.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 TILE = 256
 
@@ -39,7 +42,7 @@ def _closure_kernel(a_row_ref, a_col_ref, a_diag_ref, out_ref, *, n_k):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def closure_step_pallas(a, interpret: bool = True):
+def closure_step_pallas(a, interpret: Optional[bool] = None):
     """One squaring step for (w, w) f32 0/1 adjacency; w % TILE == 0."""
     w = a.shape[0]
     n_k = w // TILE
@@ -54,5 +57,5 @@ def closure_step_pallas(a, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((w, w), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, a, a)

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +10,9 @@ import jax.numpy as jnp
 from repro.kernels.closure.kernel import TILE, closure_step_pallas
 
 
-def transitive_closure(adj, include_self: bool = True, interpret: bool = True):
+def transitive_closure(
+    adj, include_self: bool = True, interpret: Optional[bool] = None
+):
     """(..., w, w) weighted adjacency -> boolean closure, via the Pallas
     blocked-squaring kernel.  Batched over leading dims (the d sketches)."""
     w = adj.shape[-1]

@@ -11,10 +11,13 @@ Grid (d, w/TW, n/CN) with the chunk axis innermost (accumulation).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 TILE_W = 256
 CHUNK_N = 1024
@@ -44,7 +47,7 @@ def _cs_kernel(h_ref, s_ref, v_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
-def countsketch_pallas(vec, h, s, width: int, interpret: bool = True):
+def countsketch_pallas(vec, h, s, width: int, interpret: Optional[bool] = None):
     """vec (n,) f32; h (d, n) int32; s (d, n) int32 ±1 -> (d, width) f32.
     width % TILE_W == 0 and n % CHUNK_N == 0 (ops.py pads)."""
     d, n = h.shape
@@ -59,5 +62,5 @@ def countsketch_pallas(vec, h, s, width: int, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, TILE_W), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((d, width), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(h, s, vec)

@@ -1,13 +1,15 @@
 """jit'd wrapper: hash + pad + Pallas CountSketch."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.countsketch.kernel import CHUNK_N, TILE_W, countsketch_pallas
 
 
-def countsketch(vec, hash_family, interpret: bool = True):
+def countsketch(vec, hash_family, interpret: Optional[bool] = None):
     """Compress a flat vector with a HashFamily -> (d, w) table.  Exactly
     matches repro.train.compression._sketch (tested)."""
     n = vec.shape[0]

@@ -9,10 +9,13 @@ accumulates into the two outputs.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 TILE_R = 256
 TILE_C = 256
@@ -36,7 +39,7 @@ def _flow_kernel(counters_ref, out_row_ref, out_col_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def flows_pallas(counters, interpret: bool = True):
+def flows_pallas(counters, interpret: Optional[bool] = None):
     d, wr, wc = counters.shape
     grid = (d, wr // TILE_R, wc // TILE_C)
     return pl.pallas_call(
@@ -51,5 +54,5 @@ def flows_pallas(counters, interpret: bool = True):
             jax.ShapeDtypeStruct((d, wr), jnp.float32),
             jax.ShapeDtypeStruct((d, wc), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(counters)

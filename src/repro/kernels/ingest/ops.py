@@ -12,6 +12,5 @@ from repro.core.ingest import ingest
 def sketch_ingest(counters, rows, cols, weights):
     """counters (d, wr, wc) f32 += scatter(rows, cols, weights).  Any shapes;
     equals ref.sketch_ingest_ref exactly for integer-valued weights.
-    Interpret-vs-compiled is resolved centrally from the platform by the
-    engine (interpret off TPU)."""
+    Interpret-vs-compiled follows the platform (``repro.kernels``)."""
     return ingest(counters, rows, cols, weights, backend="pallas")

@@ -4,16 +4,18 @@ Owns padding/unpadding around the Pallas kernel and the platform dispatch:
 the compiled kernel on TPU, the bit-identical jnp ref twin elsewhere (the
 interpret-mode kernel is a correctness artifact for tests, far too slow to
 serve a session from), with ``interpret=True`` forcing the kernel body on
-CPU for the bit-equality sweeps.
+CPU for the bit-equality sweeps.  Where the kernel would run, a padded
+column width past ``MAX_FUSED_WC`` is refused rather than served by the
+twin, so a session that asks for the fused kernel gets it or an error.
 """
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.ingest import pad_to
+from repro.kernels import resolve_interpret
 from repro.kernels.ingest_fused.kernel import (
     CHUNK_B,
     LANE,
@@ -23,7 +25,7 @@ from repro.kernels.ingest_fused.kernel import (
 from repro.kernels.ingest_fused.ref import fused_ingest_ref
 
 # Past this padded column width the full-width VMEM stripe (counter tile +
-# one-hot cols) no longer fits comfortably; fall back to the ref twin.
+# one-hot cols) no longer fits the kernel's VMEM budget.
 MAX_FUSED_WC = 2048
 
 
@@ -36,22 +38,23 @@ def fused_ingest(
     weights,           # (B,) f32
     *,
     interpret: Optional[bool] = None,
-    use_kernel: Optional[bool] = None,
 ):
-    """One-pass fused ingest (see kernel.py).  Any shapes; returns
+    """One-pass fused ingest (see kernel.py).  Any shapes up to a padded
+    column width of ``MAX_FUSED_WC`` where the kernel runs; returns
     ``(counters, row_flows, col_flows, touched)`` with touched (d, wr)
     bool.  Bit-identical to :func:`fused_ingest_ref` for integer-valued
     weights (property-tested)."""
     d, wr, wc = counters.shape
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu" or interpret is not None
     weights = weights.astype(jnp.float32)
     rows = rows.astype(jnp.int32)
     cols = cols.astype(jnp.int32)
-    if not use_kernel or wc + (-wc) % LANE > MAX_FUSED_WC:
+    if interpret is None and resolve_interpret():
         return fused_ingest_ref(counters, row_flows, col_flows, rows, cols, weights)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    if wc + (-wc) % LANE > MAX_FUSED_WC:
+        raise ValueError(
+            f"fused ingest serves column widths up to {MAX_FUSED_WC}, got "
+            f"{wc}; open the session with another ingest_backend"
+        )
     cp = pad_to(pad_to(counters.astype(jnp.float32), TILE_R, 1), LANE, 2)
     rfp = pad_to(row_flows.astype(jnp.float32), TILE_R, 1)
     cfp = pad_to(col_flows.astype(jnp.float32), LANE, 1)

@@ -7,7 +7,8 @@ multi-query kernel — the min-reduce happens inside the kernel pass, used by
 """
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
 from repro.core.ingest import pad_to
@@ -27,7 +28,7 @@ def _pad_all(counters, rows, cols):
     return cp, rp, cl
 
 
-def edge_query_cells(counters, rows, cols, interpret: bool = True):
+def edge_query_cells(counters, rows, cols, interpret: Optional[bool] = None):
     """Per-sketch cell values (d, Q) — matches ref.edge_query_ref exactly."""
     q = rows.shape[1]
     cp, rp, cl = _pad_all(counters, rows, cols)
@@ -35,7 +36,7 @@ def edge_query_cells(counters, rows, cols, interpret: bool = True):
     return out[:, :q]
 
 
-def edge_query_min(counters, rows, cols, interpret: bool = True):
+def edge_query_min(counters, rows, cols, interpret: Optional[bool] = None):
     """Fused min-merged estimates (Q,) — matches ref.edge_query_min_ref.
     Padded queries hit bucket (0, 0) and are sliced away."""
     q = rows.shape[1]
@@ -43,7 +44,7 @@ def edge_query_min(counters, rows, cols, interpret: bool = True):
     return multi_query_pallas(cp, rp, cl, interpret=interpret)[:q]
 
 
-def edge_query(sketch, src_keys, dst_keys, interpret: bool = True):
+def edge_query(sketch, src_keys, dst_keys, interpret: Optional[bool] = None):
     """Full f̃_e path on the fused kernel: hash → gather+min in one pass."""
     r, c = sketch.hash_edges(src_keys, dst_keys)
     return edge_query_min(sketch.counters, r, c, interpret=interpret)

@@ -11,7 +11,10 @@ tagged with zipf-distributed tenant ids and served by one
 :class:`repro.fleet.SketchFleet` — every mixed batch is a single stacked
 device dispatch, a few hot tenants carry standing subscriptions, and the
 driver prints fleet-wide throughput plus the one-compile ingest cache
-stat (DESIGN.md Section 11)."""
+stat (DESIGN.md Section 11).
+
+``main(argv)`` also returns the stats it prints, so an in-process caller
+(``chip_smoke.py``) can check them."""
 from __future__ import annotations
 
 import argparse
@@ -22,9 +25,10 @@ from repro.api import GraphStream, Query, QueryBatch, SketchConfig
 from repro.core.ingest import BACKENDS
 from repro.core.query_engine import QUERY_BACKENDS
 from repro.data.graphs import edge_stream
+from repro.launch.compile_cache import enable_compile_cache
 
 
-def main():
+def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--width", type=int, default=1024)
     ap.add_argument("--depth", type=int, default=4)
@@ -77,7 +81,8 @@ def main():
         help="bounded out-of-orderness: edges older than the watermark "
         "minus this are late (retracted via the turnstile-delete path)",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = SketchConfig(depth=args.depth, width_rows=args.width, width_cols=args.width)
     if args.tenants:
@@ -138,9 +143,13 @@ def main():
         f"closure full={stream.engine.closure_refreshes} "
         f"incremental={stream.engine.closure_incremental_refreshes}"
     )
+    stats.update(
+        ingest_backend=stream.ingest_backend, query_backend=stream.engine.backend
+    )
+    return stats
 
 
-def _serve_fleet(cfg: SketchConfig, args) -> None:
+def _serve_fleet(cfg: SketchConfig, args) -> dict:
     from repro.fleet import SketchFleet
 
     fleet = SketchFleet.open(
@@ -180,11 +189,16 @@ def _serve_fleet(cfg: SketchConfig, args) -> None:
 
     stats = fleet.summary()
     print("[serve-fleet] " + " ".join(f"{k}={v:,.1f}" for k, v in stats.items()))
-    print(
-        f"[serve-fleet] ingest compiles={fleet._ingest._cache_size()} "
-        f"dispatches={fleet._ingest.dispatches} "
-        f"subs={[s.ticks for s in subs]} ticks"
+    stats.update(
+        ingest_compiles=fleet._ingest._cache_size(),
+        sub_ticks=[s.ticks for s in subs],
     )
+    print(
+        f"[serve-fleet] ingest compiles={stats['ingest_compiles']} "
+        f"dispatches={fleet._ingest.dispatches} "
+        f"subs={stats['sub_ticks']} ticks"
+    )
+    return stats
 
 
 if __name__ == "__main__":

@@ -12,9 +12,8 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
-
-from repro.distributed.compat import shard_map
 
 
 # ---------------------------------------------------------------------------

@@ -66,12 +66,10 @@ def test_no_host_callback_positive_and_negative():
 
 
 def test_no_wide_dtype_positive_and_negative():
-    from jax.experimental import enable_x64
-
     x = jnp.ones(4)
 
     def dirty(a):
-        with enable_x64():
+        with jax.enable_x64():
             return a.astype(jnp.float64) * 2.0
 
     bad = check_entry_point(
@@ -109,11 +107,11 @@ def test_no_counter_reduction_positive_and_negative():
 
 
 def test_collectives_only_under_shard_map():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
-    # pmap's psum sits OUTSIDE any shard_map region -> violation
-    naked = jax.pmap(lambda x: jax.lax.psum(x, "i"), axis_name="i")
+    # a vmapped psum sits OUTSIDE any shard_map region -> violation
+    naked = jax.vmap(lambda x: jax.lax.psum(x, "i"), axis_name="i")
     bad = check_entry_point(
         _ep(
             "fix.naked_psum",

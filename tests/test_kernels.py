@@ -259,3 +259,18 @@ def test_sketch_pallas_backend_via_core_api():
     a = sk.update(src, dst, backend="scatter")
     b = sk.update(src, dst, backend="pallas")
     np.testing.assert_array_equal(np.asarray(a.counters), np.asarray(b.counters))
+
+
+def test_fused_ingest_refuses_widths_past_its_kernel():
+    """Where the kernel runs, a column width it cannot serve is an error,
+    not a silent hand-off to the jnp twin."""
+    from repro.kernels.ingest_fused.ops import MAX_FUSED_WC
+
+    wc = MAX_FUSED_WC + 1
+    counters = jnp.zeros((1, 8, wc), jnp.float32)
+    rows = jnp.zeros((1, 4), jnp.int32)
+    args = (counters, jnp.zeros((1, 8)), jnp.zeros((1, wc)), rows, rows, jnp.ones(4))
+    with pytest.raises(ValueError, match="column widths up to"):
+        fused_ingest(*args, interpret=True)
+    ref = fused_ingest_ref(*args)  # the twin still serves CPU sessions
+    np.testing.assert_array_equal(np.asarray(fused_ingest(*args)[0]), np.asarray(ref[0]))
