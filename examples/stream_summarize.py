@@ -60,6 +60,7 @@ def main():
         rel_err.extend((np.abs(est - exact) / exact).tolist())
         assert np.all(est >= exact - 1e-4), "over-estimate invariant violated"
 
+    gs.flush()
     wall = time.time() - t_start
     st = gs.summary()
     # exact per-edge counters for this stream would need one counter per
@@ -68,8 +69,8 @@ def main():
     eps, delta = cfg.error_bound()
     print(
         f"[stream_summarize] {args.edges:,} edges in {wall:.1f}s wall | "
-        f"ingest {st['ingest_edges_per_s']:,.0f} edges/s | "
-        f"{st['queries_served']:,} queries at {st['queries_per_s']:,.0f}/s | "
+        f"{args.edges / wall:,.0f} edges/s | "
+        f"{st['queries_served']:,} queries, {st['queries_served'] / wall:,.0f}/s | "
         f"{st['closure_refreshes']:.0f} closure refreshes"
     )
     print(

@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.query import Query, QueryBatch, QueryResult, error_bound_for
 from repro.core.query_engine import QueryEngine
@@ -69,6 +70,10 @@ class _FamilyPlan:
     items: Tuple[Tuple[int, Query], ...]
     sizes: Tuple[int, ...]
     args: Tuple  # fused device arrays, family-shaped
+
+    @property
+    def span(self) -> str:
+        return f"glava.query.{self.family}"
 
 
 class CompiledPlan:
@@ -135,35 +140,45 @@ class CompiledPlan:
             return []
         values: List = [None] * len(self.batch)
         for fp in self._plans:
-            if fp.family == "edge":
-                out = np.asarray(engine.edge(sketch, *fp.args))
-                _scatter(values, fp.items, out, fp.sizes)
-            elif fp.family in ("in_flow", "out_flow", "flow"):
-                out = np.asarray(getattr(engine, fp.family)(sketch, *fp.args))
-                _scatter(values, fp.items, out, fp.sizes)
-            elif fp.family == "heavy":
-                in_h, out_h = engine.heavy_rel_vec(sketch, *fp.args)
-                in_h, out_h = np.asarray(in_h), np.asarray(out_h)
-                lo = 0
-                for (idx, q), n in zip(fp.items, fp.sizes):
-                    i_part, o_part = in_h[lo : lo + n], out_h[lo : lo + n]
-                    values[idx] = (
-                        (i_part[0], o_part[0]) if q.scalar else (i_part, o_part)
-                    )
-                    lo += n
-            elif fp.family == "reach":
-                out = np.asarray(engine.reach(sketch, *fp.args, epoch=epoch))
-                _scatter(values, fp.items, out, fp.sizes)
-            elif fp.family == "subgraph":
-                out = np.asarray(engine.subgraph_batch(sketch, *fp.args))
-                for row, (idx, _) in enumerate(fp.items):
-                    values[idx] = out[row]
+            n = int(fp.args[0].shape[0])
+            # Subgraph families run at their exact (n, k) shape; the rest pad.
+            padded = n if fp.family == "subgraph" else engine.padded_len(n)
+            with TraceAnnotation(fp.span, queries=n, padded=padded):
+                self._run_family(fp, engine, sketch, epoch, values)
 
         bounds = {f: error_bound_for(f, sketch.config) for f in self.groups}
         return [
             QueryResult(query=q, value=values[i], error=bounds[q.family])
             for i, q in enumerate(self.batch)
         ]
+
+    @staticmethod
+    def _run_family(fp: _FamilyPlan, engine, sketch, epoch, values: List) -> None:
+        """One family's fused dispatch, fetched to the host and scattered
+        onto its request slots in ``values``."""
+        if fp.family == "edge":
+            out = np.asarray(engine.edge(sketch, *fp.args))
+            _scatter(values, fp.items, out, fp.sizes)
+        elif fp.family in ("in_flow", "out_flow", "flow"):
+            out = np.asarray(getattr(engine, fp.family)(sketch, *fp.args))
+            _scatter(values, fp.items, out, fp.sizes)
+        elif fp.family == "heavy":
+            in_h, out_h = engine.heavy_rel_vec(sketch, *fp.args)
+            in_h, out_h = np.asarray(in_h), np.asarray(out_h)
+            lo = 0
+            for (idx, q), n in zip(fp.items, fp.sizes):
+                i_part, o_part = in_h[lo : lo + n], out_h[lo : lo + n]
+                values[idx] = (
+                    (i_part[0], o_part[0]) if q.scalar else (i_part, o_part)
+                )
+                lo += n
+        elif fp.family == "reach":
+            out = np.asarray(engine.reach(sketch, *fp.args, epoch=epoch))
+            _scatter(values, fp.items, out, fp.sizes)
+        elif fp.family == "subgraph":
+            out = np.asarray(engine.subgraph_batch(sketch, *fp.args))
+            for row, (idx, _) in enumerate(fp.items):
+                values[idx] = out[row]
 
 
 def compile_batch(batch: QueryBatch) -> CompiledPlan:

@@ -46,6 +46,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.codec import encode_label, encode_labels
 from repro.api.planner import execute
@@ -97,13 +98,11 @@ LATE_POLICIES = ("retract", "drop")
 
 @dataclasses.dataclass
 class StreamStats:
-    """Session counters (ingest/query throughput, closure refreshes,
-    subscription ticks)."""
+    """Session counters (edges ingested, queries served, closure
+    refreshes, subscription ticks)."""
 
     edges_ingested: int = 0
-    ingest_s: float = 0.0
     queries_served: int = 0
-    query_s: float = 0.0
     closure_refreshes: int = 0
     closure_incremental_refreshes: int = 0
     subscription_ticks: int = 0
@@ -112,9 +111,7 @@ class StreamStats:
     def summary(self) -> Dict[str, float]:
         return {
             "edges_ingested": self.edges_ingested,
-            "ingest_edges_per_s": self.edges_ingested / max(self.ingest_s, 1e-9),
             "queries_served": self.queries_served,
-            "queries_per_s": self.queries_served / max(self.query_s, 1e-9),
             "closure_refreshes": self.closure_refreshes,
             "closure_incremental_refreshes": self.closure_incremental_refreshes,
             "subscription_ticks": self.subscription_ticks,
@@ -573,20 +570,12 @@ class GraphStream:
         new = jax.tree_util.tree_unflatten(self._live_treedef, new_leaves)
         return new, token, touched
 
-    def _dispatch_update_pre(self, live, pre):
-        """One donated dispatch of a host-collapsed batch (PreaggBatch).
-        Zero-weight bucket padding is exact: counters never hold -0.0, so
-        adding +0.0 anywhere is the identity."""
-        s = jnp.asarray(pad_bucket(pre.src))
-        d = jnp.asarray(pad_bucket(pre.dst))
-        w = jnp.asarray(pad_bucket(pre.weights))
-        su = jnp.asarray(pad_bucket(pre.src_unique))
-        sw = jnp.asarray(pad_bucket(pre.src_totals))
-        du = jnp.asarray(pad_bucket(pre.dst_unique))
-        dw = jnp.asarray(pad_bucket(pre.dst_totals))
+    def _dispatch_update_pre(self, live, staged):
+        """One donated dispatch of a host-collapsed batch: the PreaggBatch's
+        pairs, weights and marginals, bucket-padded and on the device."""
         leaves = jax.tree_util.tree_leaves(live)
         uniq = tuple(leaves[i] for i in self._uniq_leaf_idx)
-        new_leaves, token = self._jit_update_pre(uniq, s, d, w, su, sw, du, dw)
+        new_leaves, token = self._jit_update_pre(uniq, *staged)
         return jax.tree_util.tree_unflatten(self._live_treedef, new_leaves), token
 
     def ingest(
@@ -613,6 +602,15 @@ class GraphStream:
         set (the rows it wrote) — the delta the incremental closure refresh
         consumes — plus the event-time fields (watermark, late counts, WAL
         seq) when those planes are active."""
+        with TraceAnnotation("glava.ingest", epoch=self._epoch + 1) as span:
+            with TraceAnnotation("glava.ingest.encode"):
+                args = self._encode(src, dst, weights, timestamps, source)
+            span.set_metadata(edges=int(args[0].shape[0]))
+            return self._ingest_encoded(*args)
+
+    def _encode(self, src, dst, weights, timestamps, source):
+        """Labels to uint32 keys, weights and event times to their dtypes,
+        the source label to its key: ``_ingest_encoded``'s arguments."""
         s_np = np.atleast_1d(encode_labels(src))
         d_np = np.atleast_1d(encode_labels(dst))
         if s_np.shape != d_np.shape:
@@ -642,7 +640,7 @@ class GraphStream:
         source_key = (
             DEFAULT_SOURCE if source is None else int(encode_label(source))
         )
-        return self._ingest_encoded(s_np, d_np, w_np, ts_np, source_key)
+        return s_np, d_np, w_np, ts_np, source_key
 
     def _ingest_encoded(
         self,
@@ -656,19 +654,19 @@ class GraphStream:
         already uint32, the source label is already hashed).  Appends to
         the WAL FIRST — before any device dispatch — so an acknowledged
         batch is always recoverable."""
-        t0 = time.time()
         n_edges = int(s_np.shape[0])
         wal_seq = None
         if self._wal is not None and not self._replaying:
-            wal_seq = self._wal.append_edges(
-                s_np, d_np, w_np, ts_np, source_key=source_key
-            )
+            with TraceAnnotation("glava.ingest.wal"):
+                wal_seq = self._wal.append_edges(
+                    s_np, d_np, w_np, ts_np, source_key=source_key
+                )
         ev_min = ev_max = None
         if ts_np is not None and n_edges:
             ev_min, ev_max = float(ts_np.min()), float(ts_np.max())
         if self._tracker is not None:
             return self._ingest_eventtime(
-                t0, s_np, d_np, w_np, ts_np, source_key,
+                s_np, d_np, w_np, ts_np, source_key,
                 ev_min=ev_min, ev_max=ev_max, wal_seq=wal_seq,
             )
         additive = not bool(np.any(w_np < 0))
@@ -678,7 +676,13 @@ class GraphStream:
         # slot per distinct endpoint.  Exact for signed weights.
         pre = None
         if resolve_preagg(self._preagg, batch=n_edges):
-            pre = preaggregate_host(s_np, d_np, w_np)
+            with TraceAnnotation("glava.ingest.preagg") as span:
+                pre = preaggregate_host(s_np, d_np, w_np)
+                span.set_metadata(
+                    pairs=int(pre.src.size),
+                    sources=int(pre.src_unique.size),
+                    destinations=int(pre.dst_unique.size),
+                )
         # Only pay the host-side unique scan while a touched-key delta can
         # still be consumed; once tracking is poisoned (prior delete /
         # overflow, no closure sync since) the set is discarded anyway and
@@ -687,79 +691,69 @@ class GraphStream:
         # their delta is the kernel's device-emitted bitmap.
         touched = None
         if self._touched is not None and additive and not self._fused:
-            if pre is not None:
-                if self.config.directed:
-                    touched = pre.src_unique
+            with TraceAnnotation("glava.ingest.touched"):
+                if pre is not None:
+                    if self.config.directed:
+                        touched = pre.src_unique
+                    else:
+                        touched = np.unique(
+                            np.concatenate([pre.src_unique, pre.dst_unique])
+                        )
+                    if touched.size > self.config.width_rows:
+                        touched = None
                 else:
-                    touched = np.unique(
-                        np.concatenate([pre.src_unique, pre.dst_unique])
+                    touched = touched_row_keys(
+                        s_np,
+                        None if self.config.directed else d_np,
+                        cap=self.config.width_rows,
                     )
-                if touched.size > self.config.width_rows:
-                    touched = None
-            else:
-                touched = touched_row_keys(
-                    s_np,
-                    None if self.config.directed else d_np,
-                    cap=self.config.width_rows,
-                )
-        touched_rows = None
         if self._mesh is not None:
-            from repro.core.distributed import distributed_ingest
-
             self.flush()
-            if pre is not None:
-                # Bucket padding (zero weights are the identity) bounds the
-                # shapes the sharded ingest compiles for, as on one device.
+        with TraceAnnotation("glava.ingest.transfer") as span:
+            # Bucket padding of a collapsed batch (zero weights are the
+            # identity: counters never hold -0.0) bounds the shapes the
+            # update compiles for.  Fused sessions take the pairs alone.
+            if pre is None:
+                staged = (s_np, d_np, w_np)
+            else:
+                staged = (pre.src, pre.dst, pre.weights)
+                if not self._fused:
+                    staged += (
+                        pre.src_unique, pre.src_totals,
+                        pre.dst_unique, pre.dst_totals,
+                    )
+                staged = tuple(map(pad_bucket, staged))
+            staged = tuple(jnp.asarray(a) for a in staged)
+            span.set_metadata(slots=int(staged[0].shape[0]))
+        touched_rows = None
+        with TraceAnnotation("glava.ingest.dispatch"):
+            if self._mesh is not None:
+                from repro.core.distributed import distributed_ingest
+
                 self._sketch = distributed_ingest(
                     self._mesh,
                     self._sketch,
-                    jnp.asarray(pad_bucket(pre.src)),
-                    jnp.asarray(pad_bucket(pre.dst)),
-                    jnp.asarray(pad_bucket(pre.weights)),
+                    *staged[:3],
                     backend=self.ingest_backend,
-                    preagg_marginals=(
-                        jnp.asarray(pad_bucket(pre.src_unique)),
-                        jnp.asarray(pad_bucket(pre.src_totals)),
-                        jnp.asarray(pad_bucket(pre.dst_unique)),
-                        jnp.asarray(pad_bucket(pre.dst_totals)),
-                    ),
+                    preagg_marginals=staged[3:] or None,
                 )
+                self._inflight.append(self._sketch.counters)
             else:
-                self._sketch = distributed_ingest(
-                    self._mesh,
-                    self._sketch,
-                    jnp.asarray(s_np),
-                    jnp.asarray(d_np),
-                    jnp.asarray(w_np),
-                    backend=self.ingest_backend,
-                )
-            self._inflight.append(self._sketch.counters)
-        elif pre is not None and not self._fused:
-            live = self._window if self._window is not None else self._sketch
-            new, token = self._dispatch_update_pre(live, pre)
-            if self._window is not None:
-                self._window = new
-            else:
-                self._sketch = new
-            self._inflight.append(token)
-        else:
-            if pre is not None:  # fused + collapsed: pairs through the kernel
-                s = jnp.asarray(pad_bucket(pre.src))
-                d = jnp.asarray(pad_bucket(pre.dst))
-                w = jnp.asarray(pad_bucket(pre.weights))
-            else:
-                s, d, w = jnp.asarray(s_np), jnp.asarray(d_np), jnp.asarray(w_np)
-            live = self._window if self._window is not None else self._sketch
-            new, token, touched_rows = self._dispatch_update(live, s, d, w)
-            if self._window is not None:
-                self._window = new
-            else:
-                self._sketch = new
-            self._inflight.append(token)
-        while len(self._inflight) > self._max_inflight:
-            jax.block_until_ready(self._inflight.popleft())
+                live = self._window if self._window is not None else self._sketch
+                if pre is not None and not self._fused:
+                    new, token = self._dispatch_update_pre(live, staged)
+                else:
+                    new, token, touched_rows = self._dispatch_update(live, *staged)
+                if self._window is not None:
+                    self._window = new
+                else:
+                    self._sketch = new
+                self._inflight.append(token)
+        if len(self._inflight) > self._max_inflight:
+            with TraceAnnotation("glava.ingest.backpressure"):
+                while len(self._inflight) > self._max_inflight:
+                    jax.block_until_ready(self._inflight.popleft())
         self.stats.edges_ingested += n_edges
-        self.stats.ingest_s += time.time() - t0
         self._epoch += 1
         if self._fused:
             self._note_touched(touched_rows if additive else None)
@@ -796,7 +790,6 @@ class GraphStream:
 
     def _ingest_eventtime(
         self,
-        t0: float,
         s_np: np.ndarray,
         d_np: np.ndarray,
         w_np: np.ndarray,
@@ -885,7 +878,6 @@ class GraphStream:
         while len(self._inflight) > self._max_inflight:
             jax.block_until_ready(self._inflight.popleft())
         self.stats.edges_ingested += n_edges
-        self.stats.ingest_s += time.time() - t0
         self._epoch += 1
         self._note_touched(touched if additive else None)
         receipt = IngestReceipt(
@@ -919,12 +911,8 @@ class GraphStream:
 
     def flush(self) -> None:
         """Block until every dispatched ingest batch has landed on device."""
-        if not self._inflight:
-            return
-        t0 = time.time()
         while self._inflight:
             jax.block_until_ready(self._inflight.popleft())
-        self.stats.ingest_s += time.time() - t0
 
     # -- queries --------------------------------------------------------------
 
@@ -944,14 +932,12 @@ class GraphStream:
             # Nothing to answer: do not flush, plan, or touch the engine.
             return []
         self.flush()
-        t0 = time.time()
         if any(q.family == "reach" for q in batch):
             # Sync the closure cache from the session's touched-key delta so
             # one-shot reach pulls ride the same incremental refresh as
             # standing subscriptions instead of re-squaring the closure.
             self._ensure_closure()
         results = execute(self.engine, self._live(), batch, epoch=self._epoch)
-        self.stats.query_s += time.time() - t0
         self._count_served(results)
         self._sync_engine_stats()
         return results[0] if single else results
@@ -1074,32 +1060,38 @@ class GraphStream:
         ]
         if not due:
             return
-        self.flush()
-        t0 = time.time()
-        if any(s.plan.has_reach for s in due):
-            self._ensure_closure()
-        sketch = self._live()
-        now = time.time()
-        for sub in due:
-            results = sub.plan.run(self.engine, sketch, epoch=self._epoch)
-            event = SubscriptionEvent(
-                subscription_id=sub.id,
-                name=sub.name,
-                tick=sub.ticks + 1,
-                epoch=self._epoch,
-                timestamp=now,
-                results=tuple(results),
-                alarm=None if sub.alarm is None else bool(sub.alarm(results)),
-            )
-            if sub._deliver(event):
-                # Dedup'd re-emissions (exactly-once replay floor) still
-                # advance the subscription's progress, but never re-enter
-                # the feeds or callbacks.
-                self._event_log.push(event)
-            self.stats.subscription_ticks += 1
-            self._count_served(results)
-        self.stats.query_s += time.time() - t0
-        self._sync_engine_stats()
+        with TraceAnnotation("glava.tick", epoch=self._epoch, subscriptions=len(due)):
+            with TraceAnnotation("glava.tick.flush"):
+                self.flush()
+            if any(s.plan.has_reach for s in due):
+                with TraceAnnotation("glava.tick.closure") as span:
+                    full_before = self.engine.closure_refreshes
+                    self._ensure_closure()
+                    full = self.engine.closure_refreshes > full_before
+                    span.set_metadata(kind="full" if full else "incremental")
+            sketch = self._live()
+            for sub in due:
+                with TraceAnnotation("glava.tick.plan", subscription=sub.id):
+                    results = sub.plan.run(self.engine, sketch, epoch=self._epoch)
+                with TraceAnnotation("glava.tick.emit"):
+                    # Stamped once the plan has returned its host answers.
+                    event = SubscriptionEvent(
+                        subscription_id=sub.id,
+                        name=sub.name,
+                        tick=sub.ticks + 1,
+                        epoch=self._epoch,
+                        timestamp=time.time(),
+                        results=tuple(results),
+                        alarm=None if sub.alarm is None else bool(sub.alarm(results)),
+                    )
+                    if sub._deliver(event):
+                        # Dedup'd re-emissions (exactly-once replay floor)
+                        # still advance the subscription's progress, but
+                        # never re-enter the feeds or callbacks.
+                        self._event_log.push(event)
+                    self.stats.subscription_ticks += 1
+                    self._count_served(results)
+            self._sync_engine_stats()
 
     def _count_served(self, results) -> None:
         for r in results:
@@ -1349,10 +1341,13 @@ class GraphStream:
         try:
             for mut in self._wal.replay(after_seq=after_seq):
                 if isinstance(mut, EdgeMutation):
-                    self._ingest_encoded(
-                        mut.src, mut.dst, mut.weights, mut.timestamps,
-                        mut.source_key,
-                    )
+                    with TraceAnnotation(
+                        "glava.ingest", epoch=self._epoch + 1, edges=int(mut.src.size)
+                    ):
+                        self._ingest_encoded(
+                            mut.src, mut.dst, mut.weights, mut.timestamps,
+                            mut.source_key,
+                        )
                 elif isinstance(mut, AdvanceMutation):
                     self.advance_window()
                 else:  # MergeMutation — state entered outside this log
@@ -1374,8 +1369,7 @@ class GraphStream:
         )
 
     def summary(self) -> Dict[str, float]:
-        """Flushed session stats — the only honest read of ingest throughput
-        while ingest is double-buffered."""
+        """Flushed session counters."""
         self.flush()
         out = self.stats.summary()
         out["events_dropped"] = self.events_dropped

@@ -54,7 +54,7 @@ class SubscriptionEvent:
 
     ``tick`` counts this subscription's evaluations from 1; ``epoch`` is
     the session mutation epoch the results reflect; ``timestamp`` is the
-    host wall-clock at evaluation.  ``results`` are request-ordered
+    host wall-clock once the results are on the host.  ``results`` are request-ordered
     :class:`QueryResult`\\ s (the same objects a one-shot ``gs.query`` of
     the batch would return — bit-identical, property-tested).  ``alarm``
     is the subscription's predicate evaluated on the results, or ``None``

@@ -86,6 +86,55 @@ def _pallas_closure(counters: jax.Array):
     return transitive_closure(counters)
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: jitted, its XLA module reads ``jit_<name>``,
+    which is how a profile finds the query programs."""
+
+    def call(*args):
+        return fn(*args)
+
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
+# Chunking and padding a key batch, and slicing the answers back to the
+# asked length, are programs of the query path too, so they carry its name
+# rather than run as eager ops.
+_pad_keys = jax.jit(
+    _named(
+        lambda keys, lo, hi, pad: tuple(jnp.pad(k[lo:hi], (0, pad)) for k in keys),
+        "glava_query_pad",
+    ),
+    static_argnums=(1, 2, 3),
+)
+_take = jax.jit(
+    _named(lambda out, n: jax.tree_util.tree_map(lambda o: o[:n], out), "glava_query_take"),
+    static_argnums=1,
+)
+_concat = jax.jit(
+    _named(
+        lambda outs: jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *outs),
+        "glava_query_concat",
+    )
+)
+# Touched node keys to the (d, T) row indices of a closure refresh, T padded
+# with row 0.
+_closure_rows = jax.jit(
+    _named(
+        lambda row_hash, keys, pad: jnp.pad(row_hash(keys), ((0, 0), (0, pad))),
+        "glava_query_closure_rows",
+    ),
+    static_argnums=2,
+)
+
+
+def padded_len(q: int, pad_q: int, chunk_q: int) -> int:
+    """Key slots a padded family dispatch of ``q`` keys runs: each chunk of
+    up to ``chunk_q`` keys right-padded to a multiple of ``pad_q``."""
+    full, rest = divmod(q, chunk_q)
+    return full * (chunk_q + (-chunk_q) % pad_q) + rest + (-rest) % pad_q
+
+
 # family -> (jnp fn, pallas fn); point/flow families are O(d·Q) register
 # gathers either way, so both backends share the jnp path.
 _FAMILIES: Dict[str, Tuple[Callable, Callable]] = {
@@ -147,7 +196,8 @@ class QueryEngine:
         fn = self._jits.get(family)
         if fn is None:
             jnp_fn, pallas_fn = _FAMILIES[family]
-            fn = jax.jit(pallas_fn if self.backend == "pallas" else jnp_fn)
+            impl = pallas_fn if self.backend == "pallas" else jnp_fn
+            fn = jax.jit(_named(impl, f"glava_query_{family}"))
             self._jits[family] = fn
         return fn
 
@@ -188,6 +238,10 @@ class QueryEngine:
 
     # -- padding/chunking ----------------------------------------------------
 
+    def padded_len(self, q: int) -> int:
+        """Key slots :meth:`_run_padded` dispatches for ``q`` keys."""
+        return padded_len(q, self.pad_q, self.chunk_q)
+
     def _run_padded(
         self,
         family: str,
@@ -205,22 +259,14 @@ class QueryEngine:
         outs = []
         for lo in range(0, max(q, 1), self.chunk_q):
             hi = min(q, lo + self.chunk_q)
-            part = [k[lo:hi] for k in keys]
             n = hi - lo
             pad = (-n) % self.pad_q
-            if pad:
-                part = [jnp.pad(k, (0, pad)) for k in part]
+            part = keys if n == q and not pad else _pad_keys(keys, lo, hi, pad)
             out = fn(*sketch_args, *part, *tail_args)
-            outs.append(
-                jax.tree_util.tree_map(lambda o: o[:n], out)
-                if pad
-                else out
-            )
+            outs.append(_take(out, n) if pad else out)
         if len(outs) == 1:
             return outs[0]
-        return jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs), *outs
-        )
+        return _concat(tuple(outs))
 
     # -- query families ------------------------------------------------------
 
@@ -378,14 +424,13 @@ class QueryEngine:
             self._closure_epoch = epoch
             return self._closure
         if rows is None:
-            rows = sketch.row_hash(
-                jnp.asarray(touched_keys.astype(np.uint32, copy=False))
-            )  # (d, U)
-            pad = (-rows.shape[1]) % CLOSURE_REFRESH_PAD_T
-            if pad:
-                # Padding with row 0 is exact: an untouched row only restates
-                # paths the cached closure already contains.
-                rows = jnp.pad(rows, ((0, 0), (0, pad)))
+            # Padding with row 0 is exact: an untouched row only restates
+            # paths the cached closure already contains.
+            rows = _closure_rows(
+                sketch.row_hash,
+                touched_keys.astype(np.uint32, copy=False),
+                (-touched_keys.size) % CLOSURE_REFRESH_PAD_T,
+            )
         self._closure = self._fn("closure_refresh")(
             self._closure, sketch.counters, rows
         )

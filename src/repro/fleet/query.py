@@ -40,6 +40,7 @@ from repro.core.query_engine import (
     CLOSURE_STALENESS_BUDGET,
     DEFAULT_CHUNK_Q,
     DEFAULT_PAD_Q,
+    padded_len,
 )
 from repro.fleet.stack import FleetSketch
 
@@ -267,6 +268,10 @@ class FleetQueryEngine:
         return _FLEET_FAMILIES[family], args, shape
 
     # -- padding/chunking (same discipline as QueryEngine._run_padded) -------
+
+    def padded_len(self, q: int) -> int:
+        """Key slots :meth:`_run_padded` dispatches for ``q`` keys."""
+        return padded_len(q, self.pad_q, self.chunk_q)
 
     def _run_padded(self, family: str, head, keys, tail=()):
         self.dispatches[family] += 1

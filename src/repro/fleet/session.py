@@ -120,6 +120,9 @@ class _TenantEngineView:
     def _engine(self) -> FleetQueryEngine:
         return self._session._fleet.engine
 
+    def padded_len(self, q: int) -> int:
+        return self._engine().padded_len(q)
+
     def edge(self, state, src, dst):
         return self._engine().edge(state, self._slots(src.shape[0]), src, dst)
 
@@ -262,14 +265,12 @@ class TenantSession:
         self._touch()
         fleet = self._fleet
         fleet.flush()
-        t0 = time.time()
         if any(q.family == "reach" for q in batch):
             fleet.engine.refresh_closures(
                 fleet._state,
                 [(self._slot, self._consume_touched(), self._epoch)],
             )
         results = execute(self._view, fleet._state, batch, epoch=self._epoch)
-        self.stats.query_s += time.time() - t0
         self._count_served(results)
         return results[0] if single else results
 
@@ -884,7 +885,6 @@ class SketchFleet:
         for sess, st, ct in segments:
             sess._epoch += 1
             sess.stats.edges_ingested += ct
-            sess.stats.ingest_s += dt / len(segments)
             receipts[sess.tenant_id] = IngestReceipt(
                 epoch=sess._epoch,
                 n_edges=ct,
@@ -920,7 +920,6 @@ class SketchFleet:
         if not due:
             return
         self.flush()
-        t0 = time.time()
         reach_sessions: Dict[int, TenantSession] = {}
         for sess, sub in due:
             if sub.plan.has_reach:
@@ -933,20 +932,14 @@ class SketchFleet:
                     for sess in reach_sessions.values()
                 ],
             )
-        # The shared closure sync is charged evenly; each subscription then
-        # pays for its own replay only (per-iteration clock, so a late
-        # subscription never re-counts an earlier one's elapsed time).
-        sync_s = (time.time() - t0) / len(due)
-        now = time.time()
         for sess, sub in due:
-            t1 = time.time()
             results = sub.plan.run(sess._view, self._state, epoch=sess._epoch)
             event = SubscriptionEvent(
                 subscription_id=sub.id,
                 name=sub.name,
                 tick=sub.ticks + 1,
                 epoch=sess._epoch,
-                timestamp=now,
+                timestamp=time.time(),
                 results=tuple(results),
                 alarm=None if sub.alarm is None else bool(sub.alarm(results)),
             )
@@ -956,7 +949,6 @@ class SketchFleet:
             sess.stats.subscription_ticks += 1
             self.stats.subscription_ticks += 1
             sess._count_served(results)
-            sess.stats.query_s += sync_s + (time.time() - t1)
 
     # -- introspection ---------------------------------------------------------
 

@@ -18,6 +18,7 @@ stat (DESIGN.md Section 11).
 from __future__ import annotations
 
 import argparse
+import time
 
 import numpy as np
 
@@ -125,6 +126,7 @@ def main(argv=None) -> dict:
     )
     sub = stream.subscribe(workload, every=args.every, name="mixed-workload")
 
+    t0 = time.perf_counter()
     for lo in range(0, args.edges, args.batch):
         hi = min(args.edges, lo + args.batch)
         stream.ingest(
@@ -133,9 +135,12 @@ def main(argv=None) -> dict:
             data["weight"][lo:hi],
             timestamps=None if ts_all is None else ts_all[lo:hi],
         )
+    stream.flush()
+    wall_s = time.perf_counter() - t0
 
     ticks = sub.poll()
     stats = stream.summary()
+    stats.update(wall_s=wall_s, edges_per_s=args.edges / wall_s)
     print("[serve] " + " ".join(f"{k}={v:,.1f}" for k, v in stats.items()))
     print(
         f"[serve] subscription {sub.name!r}: {sub.ticks} ticks "
@@ -178,6 +183,7 @@ def _serve_fleet(cfg: SketchConfig, args) -> dict:
         for t in range(min(3, args.tenants))
     ]
 
+    t0 = time.perf_counter()
     for lo in range(0, args.edges, args.batch):
         hi = min(args.edges, lo + args.batch)
         fleet.ingest_mixed(
@@ -186,8 +192,11 @@ def _serve_fleet(cfg: SketchConfig, args) -> dict:
             data["dst"][lo:hi],
             data["weight"][lo:hi],
         )
+    fleet.flush()
+    wall_s = time.perf_counter() - t0
 
     stats = fleet.summary()
+    stats.update(wall_s=wall_s, edges_per_s=args.edges / wall_s)
     print("[serve-fleet] " + " ".join(f"{k}={v:,.1f}" for k, v in stats.items()))
     stats.update(
         ingest_compiles=fleet._ingest._cache_size(),

@@ -265,6 +265,17 @@ def test_engine_chunking_matches_direct(loaded_sketch):
     )
 
 
+@pytest.mark.parametrize("q", [0, 1, 8, 16, 17, 37])
+def test_padded_len_counts_the_key_slots_dispatched(loaded_sketch, q):
+    sk, src, dst = loaded_sketch
+    eng = QueryEngine("jnp", pad_q=8, chunk_q=16)
+    fn, slots = eng._fn("in_flow"), []
+    eng._jits["in_flow"] = lambda s, keys: slots.append(keys.shape[0]) or fn(s, keys)
+    got = eng.in_flow(sk, src[:q])
+    assert sum(slots) == eng.padded_len(q)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(queries.node_in_flow(sk, src[:q])))
+
+
 def test_engine_pallas_backend_matches_jnp(loaded_sketch):
     sk, src, dst = loaded_sketch
     a = QueryEngine("jnp")

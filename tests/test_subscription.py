@@ -6,6 +6,7 @@ acceptance count, staleness-budget fallback), subscription results
 bit-matching the one-shot ``gs.query`` oracle at every tick, the
 empty-QueryBatch fast path, and θ validation."""
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +137,24 @@ def test_subscription_event_cadence_and_payload():
     assert [e.tick for e in gs.events()] == [1, 2]
     assert list(gs.events()) == []  # drained
     assert sub.poll() == []
+
+
+def test_event_timestamp_is_taken_after_the_plan_answers(monkeypatch):
+    gs = _open()
+    sub = gs.subscribe(Query.in_flow(np.arange(4, dtype=np.uint32)), every=1)
+    run, answered = sub.plan.run, []
+
+    def slow_run(*args, **kw):
+        time.sleep(0.05)
+        out = run(*args, **kw)
+        answered.append(time.time())
+        return out
+
+    monkeypatch.setattr(sub.plan, "run", slow_run)
+    t0 = time.time()
+    gs.ingest([1, 2], [3, 4])
+    (event,) = sub.poll()
+    assert event.timestamp >= answered[0] >= t0 + 0.05
 
 
 def test_subscription_cancel_and_multiple_subscribers():
