@@ -578,6 +578,15 @@ class GraphStream:
         new_leaves, token = self._jit_update_pre(uniq, *staged)
         return jax.tree_util.tree_unflatten(self._live_treedef, new_leaves), token
 
+    def _kernel_steps(self, slots: int) -> int:
+        """Grid steps the ingest kernel runs for a batch of ``slots``
+        entries, one call per direction: a host integer from the shapes."""
+        from repro.kernels.ingest.kernel import grid_steps
+
+        cfg = self.config
+        calls = 1 if cfg.directed else 2
+        return calls * grid_steps(cfg.depth, cfg.width_rows, cfg.width_cols, slots)
+
     def ingest(
         self, src, dst, weights=None, *, timestamps=None, source=None
     ) -> IngestReceipt:
@@ -726,7 +735,9 @@ class GraphStream:
             staged = tuple(jnp.asarray(a) for a in staged)
             span.set_metadata(slots=int(staged[0].shape[0]))
         touched_rows = None
-        with TraceAnnotation("glava.ingest.dispatch"):
+        with TraceAnnotation("glava.ingest.dispatch") as span:
+            if self.ingest_backend == "pallas" and self._mesh is None:
+                span.set_metadata(kernel_steps=self._kernel_steps(staged[0].shape[0]))
             if self._mesh is not None:
                 from repro.core.distributed import distributed_ingest
 

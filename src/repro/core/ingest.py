@@ -30,10 +30,13 @@ Ingest-backend selection
 ``onehot``   The MXU formulation: per edge chunk of size ``chunk``,
              ``M += OneHot(r)^T @ (OneHot(c) * w)`` — a systolic matmul.
              Best for XLA:TPU without Pallas.
-``pallas``   The Pallas TPU kernel implementing the one-hot formulation
-             with explicit VMEM tiling (``repro.kernels.ingest``).  Compiled
-             on TPU hardware; on CPU hosts it runs in interpret mode (a
-             correctness artifact, not a perf claim).
+``pallas``   The Pallas TPU kernel (``repro.kernels.ingest``): the batch is
+             sorted by counter tile inside the jit, and each touched
+             (TR x TC) tile contracts only its own entries as one-hot MXU
+             matmuls, so the work follows the batch, not the sketch.
+             Untouched tiles are never read.  Compiled on TPU hardware; on
+             CPU hosts it runs in interpret mode (a correctness artifact,
+             not a perf claim).
 ``auto``     Resolves via the ``REPRO_INGEST_BACKEND`` environment
              variable if set, else ``pallas`` on TPU backends and
              ``scatter`` elsewhere.

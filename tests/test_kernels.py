@@ -15,6 +15,7 @@ from repro.kernels.countsketch.ops import countsketch
 from repro.kernels.countsketch.ref import countsketch_ref
 from repro.kernels.flow.ops import flows
 from repro.kernels.flow.ref import flows_ref
+from repro.kernels.ingest.kernel import CHUNK_B, TILE_C, TILE_R, grid_steps, group_metadata
 from repro.kernels.ingest.ops import sketch_ingest
 from repro.kernels.ingest.ref import sketch_ingest_ref
 from repro.kernels.ingest_fused.ops import fused_ingest
@@ -27,26 +28,90 @@ from repro.train.compression import CompressorConfig, init_compressor, _sketch
 RNG = np.random.default_rng(7)
 
 
-INGEST_SHAPES = [
-    (1, 64, 64, 33),
-    (2, 256, 256, 512),
-    (3, 300, 200, 1000),
-    (4, 512, 128, 2048),
+INGEST_CASES = [
+    pytest.param(1, 64, 64, 33, "uniform", id="1-64-64-33"),
+    pytest.param(2, 256, 256, 512, "uniform", id="2-256-256-512"),
+    pytest.param(3, 300, 200, 1000, "uniform", id="3-300-200-1000"),
+    pytest.param(4, 512, 128, 2048, "uniform", id="4-512-128-2048"),
+    # rows in the top half only: the lower counter tiles get no entry
+    pytest.param(2, 1024, 768, 300, "empty_tiles", id="2-1024-768-300-empty_tiles"),
+    # every row in [0, 8): two tiles hold 1,024 entries each, 8 chunks apiece
+    pytest.param(2, 512, 1024, 2048, "skewed", id="2-512-1024-2048-skewed"),
+    pytest.param(3, 256, 256, 1, "uniform", id="3-256-256-1"),
+    # row -1 slots (padding, out-of-shard rows) and zero weights are inert
+    pytest.param(2, 512, 512, 700, "padding", id="2-512-512-700-padding"),
+    pytest.param(3, 300, 200, 777, "turnstile", id="3-300-200-777-turnstile"),
 ]
 
 
-@pytest.mark.parametrize("d,wr,wc,b", INGEST_SHAPES)
-def test_ingest_kernel_matches_ref(d, wr, wc, b):
+def _ingest_expected(counters, rows, cols, w):
+    """The scatter oracle, each depth on its own so row -1 can be dropped
+    per depth (a negative index would wrap in the oracle)."""
+    return jnp.concatenate([
+        sketch_ingest_ref(
+            counters[g:g + 1],
+            jnp.maximum(rows[g:g + 1], 0),
+            cols[g:g + 1],
+            jnp.where(rows[g] >= 0, w, 0.0),
+        )
+        for g in range(counters.shape[0])
+    ])
+
+
+@pytest.mark.parametrize("d,wr,wc,b,case", INGEST_CASES)
+def test_ingest_kernel_matches_ref(d, wr, wc, b, case):
     # integer-valued counters/weights: the paper's counting regime, where the
     # kernel is bit-exact vs the scatter oracle (fp32 ints < 2**24)
     counters = jnp.asarray(RNG.integers(0, 1000, (d, wr, wc)), jnp.float32)
-    rows = jnp.asarray(RNG.integers(0, wr, (d, b)), jnp.int32)
+    row_hi = {"empty_tiles": wr // 2, "skewed": 8}.get(case, wr)
+    rows = RNG.integers(0, row_hi, (d, b))
     cols = jnp.asarray(RNG.integers(0, wc, (d, b)), jnp.int32)
-    w = jnp.asarray(RNG.integers(1, 9, b), jnp.float32)
+    w = RNG.integers(-9, 10, b) if case == "turnstile" else RNG.integers(1, 9, b)
+    if case == "padding":
+        rows[RNG.random((d, b)) < 0.3] = -1
+        w[::5] = 0
+    rows = jnp.asarray(rows, jnp.int32)
+    w = jnp.asarray(w, jnp.float32)
     np.testing.assert_array_equal(
         np.asarray(sketch_ingest(counters, rows, cols, w)),
-        np.asarray(sketch_ingest_ref(counters, rows, cols, w)),
+        np.asarray(_ingest_expected(counters, rows, cols, w)),
     )
+
+
+def test_ingest_work_list_at_base_shape():
+    """The grouped kernel's work list at the base sketch (d=5, 8192²) for
+    65,536 uniform entries: no kernel runs.  Every entry is covered once,
+    each tile's items are consecutive, and the occupied steps stay within
+    B/CB + T per depth, far below the 655,360 of a dense tile sweep."""
+    d, w, b = 5, 8192, 65536
+    rows = jnp.asarray(RNG.integers(0, w, (d, b)), jnp.int32)
+    cols = jnp.asarray(RNG.integers(0, w, (d, b)), jnp.int32)
+    weights = jnp.ones((b,), jnp.float32)
+    (r, c, _), (tile, chunk, n_items) = jax.jit(
+        group_metadata, static_argnums=(3, 4)
+    )(rows, cols, weights, w, w)
+    n_tc = w // TILE_C
+    tiles = (w // TILE_R) * n_tc
+    steps = grid_steps(d, w, w, b) // d
+    assert steps == b // CHUNK_B + tiles - 1
+    key = np.asarray((r // TILE_R) * n_tc + c // TILE_C)
+    tile = np.asarray(tile).reshape(d, steps)
+    chunk = np.asarray(chunk).reshape(d, steps)
+    n_items = np.asarray(n_items)
+    assert (n_items <= b // CHUNK_B + tiles).all()
+    assert n_items.sum() < 655_360 // 50
+    for g in range(d):
+        k, n = key[g], int(n_items[g])
+        assert (np.diff(k) >= 0).all()
+        assert (np.diff(tile[g, :n]) >= 0).all()
+        covered = sum(
+            int((k[j * CHUNK_B:(j + 1) * CHUNK_B] == t).sum())
+            for t, j in zip(tile[g, :n], chunk[g, :n])
+        )
+        assert covered == b
+        # one item per distinct tile in each chunk of the sorted entries
+        assert n == sum(len(np.unique(k[j:j + CHUNK_B])) for j in range(0, b, CHUNK_B))
+        assert (tile[g, n:] == tile[g, n - 1]).all() and (chunk[g, n:] == chunk[g, n - 1]).all()
 
 
 def test_ingest_kernel_fp_weights_close():

@@ -148,3 +148,30 @@ def test_lowered_module_names(traced):
     ):
         head = engine._fn(family).lower(sketch, *args).as_text().split("\n", 1)[0]
         assert f"@jit_glava_query_{family} " in head, head
+
+
+def test_dispatch_carries_the_ingest_kernels_grid_steps(traced, tmp_path):
+    """A session on the Pallas ingest kernel puts the kernel's static grid
+    length on ``glava.ingest.dispatch``; a scatter session puts nothing."""
+    from repro.kernels.ingest.kernel import grid_steps
+
+    _, events = traced
+    assert all(e[3] == {} for e in events if e[0] == "glava.ingest.dispatch")
+    gs = GraphStream.open("smoke", ingest_backend="pallas")
+    s, d = np.random.default_rng(1).integers(0, NODES, (2, EDGES)).astype(np.uint32)
+    with jax.profiler.trace(str(tmp_path)):
+        gs.ingest(s, d)
+        gs.flush()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = {
+        e.name: dict(e.stats)
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines
+        for e in line.events
+        if e.name in ("glava.ingest.transfer", "glava.ingest.dispatch")
+    }
+    cfg = gs.config
+    slots = spans["glava.ingest.transfer"]["slots"]
+    assert spans["glava.ingest.dispatch"] == {
+        "kernel_steps": grid_steps(cfg.depth, cfg.width_rows, cfg.width_cols, slots)
+    }
