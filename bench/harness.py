@@ -21,6 +21,7 @@ import dataclasses
 import functools
 import gc
 import pathlib
+import resource
 import statistics
 import time
 from typing import Dict, List, Optional
@@ -115,6 +116,80 @@ def require_devices(chips: int, allow_cpu: bool):
 
 def _quantile(values: np.ndarray, q: float) -> float:
     return float(np.quantile(values, q, method="linear"))
+
+
+def edges_per_s(batches: List[Batch]) -> Optional[float]:
+    """Edges of the completed batches over the time from the window's start
+    to the last completion; None when no batch completed."""
+    done = [b for b in batches if b.ok]
+    return sum(b.n for b in done) / done[-1].t_done if done else None
+
+
+def window_stats(batches: List[Batch]) -> Dict:
+    """How the window's batches were paced, for the ``[bench]`` log: tick
+    quantiles (pick-up to answers on the host), the ticks over twice the
+    median and their time past it, the completed edge rate in each quarter
+    of the time ``edges_per_s`` spans (their mean is that rate), and the
+    pick-up time between one batch's completion and the next batch's cut."""
+    ms = lambda v: round(v * 1e3, 3)  # noqa: E731
+    tick = np.array([b.t_done - b.t_pick for b in batches])
+    prev = np.array([0.0] + [b.t_done for b in batches[:-1]])
+    pickup = np.array([b.t_pick for b in batches]) - prev
+    slow = tick[tick > 2 * np.median(tick)]
+    out = dict(
+        tick_ms={f"p{q}": ms(_quantile(tick, q / 100)) for q in (50, 90, 99)} | {"max": ms(float(tick.max()))},
+        stalls=dict(n=int(slow.size), excess_ms=ms(float(np.sum(slow - np.median(tick))))),
+        pickup_ms=dict(p50=ms(_quantile(pickup, 0.5)), max=ms(float(pickup.max())),
+                       sum=ms(float(pickup.sum()))),
+        quarter_edges_per_s=[],
+    )
+    done = [b for b in batches if b.ok]
+    if done:
+        quarter = done[-1].t_done / 4
+        edges = [0] * 4
+        for b in done:
+            edges[min(int(b.t_done / quarter), 3)] += b.n
+        out["quarter_edges_per_s"] = [e / quarter for e in edges]
+    return out
+
+
+class HostWatch:
+    """What the host did during the window: Python's garbage collections
+    by generation and their total pause (``gc.callbacks``), the process's
+    CPU seconds and context switches, and the main thread's CPU seconds."""
+
+    def __init__(self):
+        self.collections = [0, 0, 0]
+        self.pause_s = 0.0
+        self._t_gc = 0.0
+        self._start = None
+
+    def _on_gc(self, phase, info):
+        if phase == "start":
+            self._t_gc = time.perf_counter()
+        else:
+            self.collections[info["generation"]] += 1
+            self.pause_s += time.perf_counter() - self._t_gc
+
+    def _snapshot(self):
+        return resource.getrusage(resource.RUSAGE_SELF), resource.getrusage(resource.RUSAGE_THREAD)
+
+    def start(self) -> None:
+        self._start = self._snapshot()
+        gc.callbacks.append(self._on_gc)
+
+    def stop(self) -> Dict:
+        gc.callbacks.remove(self._on_gc)
+        (p0, t0), (p1, t1) = self._start, self._snapshot()
+        return dict(
+            gc_collections=self.collections,
+            gc_pause_ms=round(self.pause_s * 1e3, 3),
+            cpu_user_s=round(p1.ru_utime - p0.ru_utime, 3),
+            cpu_sys_s=round(p1.ru_stime - p0.ru_stime, 3),
+            ctx_voluntary=p1.ru_nvcsw - p0.ru_nvcsw,
+            ctx_involuntary=p1.ru_nivcsw - p0.ru_nivcsw,
+            main_thread_cpu_s=round(t1.ru_utime + t1.ru_stime - t0.ru_utime - t0.ru_stime, 3),
+        )
 
 
 def run_cell(
@@ -227,7 +302,12 @@ def run_cell(
     tracer = _Tracer(trace_dir, seconds) if trace else None
     counters_start = cell.counters()
     setup_s = time.perf_counter() - t_process
-    batches = drive(cursor, seconds, tracer)
+    host = HostWatch()
+    host.start()
+    try:
+        batches = drive(cursor, seconds, tracer)
+    finally:
+        host_info = host.stop()
     cursor = batches[-1].start + batches[-1].n
     t_end = batches[-1].t_done
     compiles1 = clock.snapshot()
@@ -242,10 +322,10 @@ def run_cell(
     gc.collect()
 
     # -- end-to-end numbers ---------------------------------------------------
-    done = [b for b in batches if b.ok]
     e2e: Dict[str, float] = {"setup_s": setup_s}
-    if done:
-        e2e["edges_per_s"] = sum(b.n for b in done) / done[-1].t_done
+    rate = edges_per_s(batches)
+    if rate is not None:
+        e2e["edges_per_s"] = rate
     lat_n = 0
     backlog, backlog_q = 0, []
     if arrivals.mode == "poisson":
@@ -273,7 +353,7 @@ def run_cell(
         edges=int(sum(b.n for b in batches)),
         batch_median=float(statistics.median([b.n for b in batches])),
         batch_max=int(max(b.n for b in batches)),
-        tick_median_s=float(statistics.median([b.t_done - b.t_pick for b in batches])),
+        **window_stats(batches),
         backlog_edges=int(backlog),
         backlog_quarters=backlog_q,
         latency_samples=lat_n,
@@ -282,6 +362,7 @@ def run_cell(
         compile_events_in_window=compiles1[0] - compiles0[0],
         backend_compiles_in_window=compiles1[1] - compiles0[1],
         compile_s_in_window=round(compiles1[2] - compiles0[2], 6),
+        **host_info,
         counters=counters,
     )
     log(f"[bench] {info}")
