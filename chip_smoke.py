@@ -15,9 +15,16 @@ and checks that session against the same references.  Phase B runs
 ``repro.launch.serve`` in-process on an event-time window with a
 write-ahead log; phase C runs it in fleet mode.
 
-``--four-chips`` runs only ``GraphStream.open("base", mesh=2x2)`` against a
-single-device session in the same process: counters, registers and answers
-must be bit-identical, and the counters must span all four devices.
+``--four-chips`` runs only ``GraphStream.open("base", mesh=...)`` on two
+``("data", "model")`` meshes of the four chips, each against a
+single-device session in the same process: 2 x 2 (two stream shards whose
+partial updates merge by ``psum``, two row shards) and 1 x 4 (the layout the
+session's placement rule builds for a sketch one chip cannot hold).  On
+both, counters, registers and edge, in-flow and heavy answers must be
+bit-identical, the counters must span all four devices, and ingest must
+resolve to the Pallas kernel per shard.  On 1 x 4 the compiled programs must
+also move no counter between chips: the update has no collective, and the
+edge query's only one is its ``pmin`` over d x Q values.
 
 Each phase prints one line: resolved backends, compile and wall seconds,
 edges ingested, the device's peak bytes in use, and which Pallas kernels
@@ -315,14 +322,35 @@ def phase_fleet(tenants=64, width=1024, depth=4, edges=N_BATCHES * BATCH, batch=
     )
 
 
-def phase_mesh(preset="base", n_batches=N_BATCHES, batch=BATCH, n_nodes=N_NODES, seed=0):
-    """The 2x2 mesh session against a single-device session, bit for bit."""
+COLLECTIVES = ("all-reduce", "all-gather", "collective-permute", "reduce-scatter", "all-to-all")
+
+
+def collectives(hlo: str) -> list:
+    """The collective instructions of a compiled module's text."""
+    return [
+        line.strip()
+        for line in hlo.splitlines()
+        if any(f" {k}(" in line or f" {k}-start(" in line for k in COLLECTIVES)
+    ]
+
+
+def phase_mesh(
+    preset="base", shape=(1, 4), n_batches=N_BATCHES, batch=BATCH, n_nodes=N_NODES, seed=0
+):
+    """A session row-sharded on a ``shape`` ("data", "model") mesh of four
+    devices against a single-device session, bit for bit; on a 1 x n mesh
+    also the collectives its programs compile to."""
     from repro.api import GraphStream, Query, QueryBatch
 
     src, dst, w, rng = make_stream(seed, n_batches * batch, n_nodes)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh(shape, ("data", "model"))
+    streams, rows = shape
     dist = GraphStream.open(preset, mesh=mesh)
     local = GraphStream.open(preset)
+    check(
+        dist.ingest_backend == local.ingest_backend,
+        f"mesh ingest resolved to {dist.ingest_backend}, one device to {local.ingest_backend}",
+    )
     ref = Reference(local.sketch)
     for i in range(n_batches):
         lo, hi = i * batch, (i + 1) * batch
@@ -337,8 +365,8 @@ def phase_mesh(preset="base", n_batches=N_BATCHES, batch=BATCH, n_nodes=N_NODES,
         f"mesh counters span {len({s.device for s in shards})} devices, want 4",
     )
     check(
-        all(s.data.shape[1] == wr // 2 for s in shards),
-        f"mesh counter shards hold {[s.data.shape for s in shards]}, want w_r/2 rows",
+        all(s.data.shape[1] == wr // rows for s in shards),
+        f"mesh counter shards hold {[s.data.shape for s in shards]}, want w_r/{rows} rows",
     )
     for name in ("counters", "row_flows", "col_flows"):
         check(
@@ -350,24 +378,51 @@ def phase_mesh(preset="base", n_batches=N_BATCHES, batch=BATCH, n_nodes=N_NODES,
         )
     qs = np.concatenate([src[:768], rng.integers(0, n_nodes, 256)]).astype(np.uint32)
     qd = np.concatenate([dst[:768], rng.integers(0, n_nodes, 256)]).astype(np.uint32)
-    queries = QueryBatch([Query.edge(qs, qd), Query.in_flow(qs[:256])])
+    queries = QueryBatch(
+        [Query.edge(qs, qd), Query.in_flow(qs[:256]), Query.heavy(qs[:64], theta=THETA)]
+    )
     got, want = dist.query(queries), local.query(queries)
     for g, x in zip(got, want):
         check(
             np.array_equal(np.asarray(g.value), np.asarray(x.value)),
-            f"mesh {g.family} answers differ from the single-device session",
+            f"mesh {g.query.family} answers differ from the single-device session",
         )
     check(np.array_equal(np.asarray(got[0].value), ref.edge(qs, qd)), "mesh edge != reference")
     check(
         np.array_equal(np.asarray(got[1].value), ref.in_flow(qs[:256])),
         "mesh in-flow != reference",
     )
+    d = dist.config.depth
+    layout = f"{streams}x{rows}"
+    checked = f"counters on 4 devices, {wr // rows} rows each; bit-identical"
+    if streams > 1:
+        checked += f"; {streams} stream shards merged by psum"
+    else:
+        # The programs as compiled for this mesh: the update moves nothing
+        # between devices, the edge query one pmin of d x Q values.
+        leaves = jax.tree_util.tree_leaves(dist.sketch)
+        uniq = tuple(leaves[i] for i in dist._uniq_leaf_idx)
+        slots = jax.ShapeDtypeStruct((batch,), jnp.uint32, sharding=dist._replicated)
+        vals = jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=dist._replicated)
+        update = dist._jit_update_mesh.lower(uniq, slots, slots, vals, slots, vals, slots, vals)
+        moved = collectives(update.compile().as_text())
+        check(not moved, f"the mesh update compiles to collectives: {moved}")
+        keys = jax.ShapeDtypeStruct((qs.size,), jnp.uint32, sharding=dist._replicated)
+        edge = dist.engine._fn("edge").lower(dist.sketch, keys, keys).compile().as_text()
+        edge = collectives(edge)
+        check(
+            len(edge) == 1 and " all-reduce(" in edge[0] and f"f32[{d},{qs.size}]" in edge[0],
+            f"the edge query compiles to {edge}, want one all-reduce of f32[{d},{qs.size}]",
+        )
+        checked += (
+            f"; update: no collective; edge query: one all-reduce of {d * qs.size * 4} B"
+        )
     return dict(
-        sketch=f"{preset}: d={dist.config.depth} w={wr}x{dist.config.width_cols}, 2x2",
+        sketch=f"{preset}: d={d} w={wr}x{dist.config.width_cols}, {layout}",
         ingest_backend=f"mesh:{dist.ingest_backend} vs local:{local.ingest_backend}",
         query_backend=dist.engine.backend,
         edges=int(dist.summary()["edges_ingested"] + local.summary()["edges_ingested"]),
-        checked=f"counters on 4 devices, {wr // 2} rows each; bit-identical",
+        checked=checked,
     )
 
 
@@ -431,7 +486,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--four-chips",
         action="store_true",
-        help="run only the 2x2 mesh session and its single-device comparison",
+        help="run only the 2x2 and 1x4 mesh sessions and their single-device comparisons",
     )
     args = ap.parse_args(argv)
 
@@ -459,7 +514,13 @@ def main(argv=None) -> int:
     shutil.rmtree(OUT, ignore_errors=True)
     clock = CompileClock()
     if args.four_chips:
-        run_phase("mesh", phase_mesh, clock, SESSION_KERNELS[:2])
+        for shape in ((2, 2), (1, 4)):
+            run_phase(
+                "mesh-{}x{}".format(*shape),
+                lambda: phase_mesh(shape=shape),
+                clock,
+                SESSION_KERNELS[:2],
+            )
     else:
         run_phase("A", phase_session, clock, SESSION_KERNELS)
         run_phase("B", lambda: phase_serve(OUT / "wal"), clock, SESSION_KERNELS)

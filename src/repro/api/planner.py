@@ -143,7 +143,10 @@ class CompiledPlan:
             n = int(fp.args[0].shape[0])
             # Subgraph families run at their exact (n, k) shape; the rest pad.
             padded = n if fp.family == "subgraph" else engine.padded_len(n)
-            with TraceAnnotation(fp.span, queries=n, padded=padded):
+            stats = dict(queries=n, padded=padded)
+            if engine.mesh is not None:
+                stats["shards"] = engine.shards
+            with TraceAnnotation(fp.span, **stats):
                 self._run_family(fp, engine, sketch, epoch, values)
 
         bounds = {f: error_bound_for(f, sketch.config) for f in self.groups}

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import math
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple, Union
@@ -179,12 +180,68 @@ def _preset(name: str) -> SketchConfig:
     return presets[name]
 
 
+def _bytes_limit(device) -> Optional[int]:
+    """The memory a device holds arrays in, where its backend reports it
+    (TPU and GPU do, the CPU does not)."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+def _empty_sharded(config: SketchConfig, key, mesh) -> GLavaSketch:
+    """An empty sketch whose counter rows are sharded over ``mesh``'s model
+    axis, allocated by one jitted constructor with those output shardings:
+    each device writes only its own rows, no device ever holds a whole-size
+    copy.  The hash family and flow registers are replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    make = functools.partial(GLavaSketch.empty, config)
+    shapes = jax.eval_shape(make, key)
+    leaves, treedef = jax.tree_util.tree_flatten(shapes)
+    rows, replicated = NamedSharding(mesh, P(None, "model", None)), NamedSharding(mesh, P())
+    shardings = [rows if x is shapes.counters else replicated for x in leaves]
+    flat = jax.jit(
+        lambda k: jax.tree_util.tree_leaves(make(k)), out_shardings=shardings
+    )(key)
+    sketch = jax.tree_util.tree_unflatten(treedef, flat)
+    if config.is_square:  # one family for rows and columns, as on one device
+        sketch = dataclasses.replace(sketch, col_hash=sketch.row_hash)
+    return sketch
+
+
+def row_shards(config: SketchConfig, devices) -> int:
+    """The placement rule of :class:`GraphStream` (see its docstring): how
+    many of ``devices`` a session's counters are row-sharded over, 1 for a
+    session that stays on one device."""
+    limits = [_bytes_limit(d) for d in devices]
+    n = len(devices)
+    if n == 1 or None in limits or 2 * config.space_bytes() <= min(limits):
+        return 1
+    if config.width_rows % n:
+        raise ValueError(
+            f"{config.space_bytes()} B of counters do not fit one device "
+            f"({min(limits)} B), and their {config.width_rows} rows do not "
+            f"divide over the {n} local devices"
+        )
+    return n
+
+
 class GraphStream:
     """One graph-stream session: a summary plus its ingest/query engines.
 
     Construct via :meth:`open`.  All mutation bumps the sketch *epoch*,
     which tags the query engine's transitive-closure cache so reach
-    queries amortize one closure per quiescent period."""
+    queries amortize one closure per quiescent period.
+
+    Placement: a session opened without ``mesh=`` stays on one device while
+    its counters (d · w_r · w_c · 4 bytes) take at most half of the
+    smallest local device's memory (``memory_stats()["bytes_limit"]``),
+    which leaves room for a second whole copy; so does any session whose
+    devices report no memory (the CPU), a windowed or ``fused`` one, and
+    any on a host of one device.  Otherwise its counters are row-sharded
+    over all n local devices on a 1 × n ``("data", "model")`` mesh,
+    allocated sharded, with the hash family and flow registers replicated;
+    n must divide w_r.  A session on a mesh ingests through the donated
+    jit boundary (``auto``: the Pallas kernel per row shard) and answers
+    edge queries shard-locally (one ``pmin`` over ``model``)."""
 
     def __init__(
         self,
@@ -255,32 +312,44 @@ class GraphStream:
         self._replaying = False
         self._last_restore_meta: Dict = {}
         self.config = config
-        if window_slices:
-            self._window: Optional[SlidingWindowSketch] = SlidingWindowSketch.empty(
-                config, window_slices, jax.random.key(seed)
-            )
-            self._sketch: Optional[GLavaSketch] = None
-        else:
-            self._window = None
-            self._sketch = GLavaSketch.empty(config, jax.random.key(seed))
-        if mesh is not None:
-            # On a mesh, `auto` picks the paths that run on row-sharded
-            # counters: the compiler cannot partition a Mosaic kernel
-            # (queries), and the ingest kernel's outputs carry no shard_map
-            # varying-axes type yet.
-            if ingest_backend in (None, "auto"):
-                ingest_backend = "onehot"
-            if query_backend in (None, "auto"):
-                query_backend = "jnp"
-            # The estimators then run on the sharded counters as on one
-            # device: Auto axes let the compiler partition them, where
-            # Explicit axes (jax.make_mesh's default) would demand an output
-            # sharding for every gather.
-            mesh = jax.sharding.Mesh(
-                mesh.devices,
-                mesh.axis_names,
-                axis_types=(jax.sharding.AxisType.Auto,) * mesh.devices.ndim,
-            )
+        with TraceAnnotation("glava.open.place") as span:
+            if mesh is None and not window_slices and ingest_backend != "fused":
+                devices = jax.local_devices()
+                n = row_shards(config, devices)
+                if n > 1:
+                    mesh = jax.sharding.Mesh(
+                        np.asarray(devices).reshape(1, n), ("data", "model")
+                    )
+            if mesh is not None:
+                # The estimators run on the sharded counters as on one
+                # device: Auto axes let the compiler partition them, where
+                # Explicit axes (jax.make_mesh's default) would demand an
+                # output sharding for every gather.
+                mesh = jax.sharding.Mesh(
+                    mesh.devices,
+                    mesh.axis_names,
+                    axis_types=(jax.sharding.AxisType.Auto,) * mesh.devices.ndim,
+                )
+            shards = 1 if mesh is None else mesh.shape["model"]
+            span.set_metadata(bytes_per_device=config.space_bytes() // shards)
+            if window_slices:
+                self._window: Optional[SlidingWindowSketch] = (
+                    SlidingWindowSketch.empty(config, window_slices, jax.random.key(seed))
+                )
+                self._sketch: Optional[GLavaSketch] = None
+            else:
+                self._window = None
+                key = jax.random.key(seed)
+                self._sketch = (
+                    GLavaSketch.empty(config, key)
+                    if mesh is None
+                    else _empty_sharded(config, key, mesh)
+                )
+        if mesh is not None and query_backend in (None, "auto"):
+            # The compiler cannot partition a Mosaic kernel: the register
+            # families run as XLA gathers, and the edge family shard-locally
+            # (QueryEngine with a mesh).
+            query_backend = "jnp"
         # "fused" is a session-level mode, not an IngestEngine backend: the
         # one-pass kernel updates counters + registers + touched bitmap
         # together, which only a plain local session can consume.
@@ -294,9 +363,10 @@ class GraphStream:
         # dispatch ("auto" honours REPRO_INGEST_PREAGG, else batches >=
         # PREAGG_MIN_BATCH) — the heavy-tail ingest fast path.
         self._preagg = preagg
-        self.engine = QueryEngine(query_backend)
+        self.engine = QueryEngine(query_backend, mesh=mesh)
         self.stats = StreamStats()
         self._mesh = mesh
+        self._shards = shards
         self._epoch = 0
         # Standing-query plane: registered subscriptions, the session-wide
         # event feed, and the touched-key accumulator feeding the
@@ -374,6 +444,26 @@ class GraphStream:
         # (pad_bucket) so variable collapse sizes cost a bounded trace
         # ladder, not a retrace per batch.
         self._jit_update_pre = jax.jit(_update_pre, donate_argnums=0)
+
+        if mesh is not None:
+            from repro.core.distributed import distributed_ingest
+
+            # The mesh's donated boundary, raw or host-collapsed batches
+            # alike: each device updates its row shard in place.
+            def _update_mesh(uniq, s, d, w, *marginals):
+                live = jax.tree_util.tree_unflatten(
+                    treedef, [uniq[j] for j in slots]
+                )
+                new = distributed_ingest(
+                    mesh, live, s, d, w,
+                    backend=backend, preagg_marginals=marginals or None,
+                )
+                return jax.tree_util.tree_leaves(new), jnp.sum(w)
+
+            self._jit_update_mesh = jax.jit(_update_mesh, donate_argnums=0)
+            self._replicated = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec()
+            )
 
         # Window expiry boundary: advancing the ring is pure data movement
         # over the (K, d, w_r, w_c) slices, so donating the window lets XLA
@@ -570,22 +660,33 @@ class GraphStream:
         new = jax.tree_util.tree_unflatten(self._live_treedef, new_leaves)
         return new, token, touched
 
+    def _donated(self, jit_fn, live, staged):
+        """One dispatch through a donated boundary that returns (leaves,
+        token): live pytree -> (new live, token)."""
+        leaves = jax.tree_util.tree_leaves(live)
+        uniq = tuple(leaves[i] for i in self._uniq_leaf_idx)
+        new_leaves, token = jit_fn(uniq, *staged)
+        return jax.tree_util.tree_unflatten(self._live_treedef, new_leaves), token
+
     def _dispatch_update_pre(self, live, staged):
         """One donated dispatch of a host-collapsed batch: the PreaggBatch's
         pairs, weights and marginals, bucket-padded and on the device."""
-        leaves = jax.tree_util.tree_leaves(live)
-        uniq = tuple(leaves[i] for i in self._uniq_leaf_idx)
-        new_leaves, token = self._jit_update_pre(uniq, *staged)
-        return jax.tree_util.tree_unflatten(self._live_treedef, new_leaves), token
+        return self._donated(self._jit_update_pre, live, staged)
 
     def _kernel_steps(self, slots: int) -> int:
         """Grid steps the ingest kernel runs for a batch of ``slots``
-        entries, one call per direction: a host integer from the shapes."""
+        entries on each device, one call per direction: a host integer from
+        the shapes.  On a mesh every row shard runs the grid of its rows
+        and its stream shard's entries, out-of-shard entries included."""
         from repro.kernels.ingest.kernel import grid_steps
 
         cfg = self.config
         calls = 1 if cfg.directed else 2
-        return calls * grid_steps(cfg.depth, cfg.width_rows, cfg.width_cols, slots)
+        rows, per_device = cfg.width_rows, slots
+        if self._mesh is not None:
+            rows //= self._shards
+            per_device //= self._mesh.size // self._shards
+        return calls * grid_steps(cfg.depth, rows, cfg.width_cols, per_device)
 
     def ingest(
         self, src, dst, weights=None, *, timestamps=None, source=None
@@ -716,8 +817,6 @@ class GraphStream:
                         None if self.config.directed else d_np,
                         cap=self.config.width_rows,
                     )
-        if self._mesh is not None:
-            self.flush()
         with TraceAnnotation("glava.ingest.transfer") as span:
             # Bucket padding of a collapsed batch (zero weights are the
             # identity: counters never hold -0.0) bounds the shapes the
@@ -732,34 +831,28 @@ class GraphStream:
                         pre.dst_unique, pre.dst_totals,
                     )
                 staged = tuple(map(pad_bucket, staged))
-            staged = tuple(jnp.asarray(a) for a in staged)
+            if self._mesh is None:
+                staged = tuple(jnp.asarray(a) for a in staged)
+            else:
+                staged = tuple(jax.device_put(staged, self._replicated))
             span.set_metadata(slots=int(staged[0].shape[0]))
         touched_rows = None
         with TraceAnnotation("glava.ingest.dispatch") as span:
-            if self.ingest_backend == "pallas" and self._mesh is None:
+            if self.ingest_backend == "pallas":
                 span.set_metadata(kernel_steps=self._kernel_steps(staged[0].shape[0]))
+            live = self._window if self._window is not None else self._sketch
             if self._mesh is not None:
-                from repro.core.distributed import distributed_ingest
-
-                self._sketch = distributed_ingest(
-                    self._mesh,
-                    self._sketch,
-                    *staged[:3],
-                    backend=self.ingest_backend,
-                    preagg_marginals=staged[3:] or None,
-                )
-                self._inflight.append(self._sketch.counters)
+                span.set_metadata(shards=self._shards)
+                new, token = self._donated(self._jit_update_mesh, live, staged)
+            elif pre is not None and not self._fused:
+                new, token = self._dispatch_update_pre(live, staged)
             else:
-                live = self._window if self._window is not None else self._sketch
-                if pre is not None and not self._fused:
-                    new, token = self._dispatch_update_pre(live, staged)
-                else:
-                    new, token, touched_rows = self._dispatch_update(live, *staged)
-                if self._window is not None:
-                    self._window = new
-                else:
-                    self._sketch = new
-                self._inflight.append(token)
+                new, token, touched_rows = self._dispatch_update(live, *staged)
+            if self._window is not None:
+                self._window = new
+            else:
+                self._sketch = new
+            self._inflight.append(token)
         if len(self._inflight) > self._max_inflight:
             with TraceAnnotation("glava.ingest.backpressure"):
                 while len(self._inflight) > self._max_inflight:

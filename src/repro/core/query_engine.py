@@ -29,6 +29,7 @@ together.
 from __future__ import annotations
 
 import collections
+import functools
 import os
 from typing import Callable, Dict, Optional, Tuple
 
@@ -172,8 +173,15 @@ class QueryEngine:
         chunk_q: int = DEFAULT_CHUNK_Q,
         closure_staleness_budget: int = CLOSURE_STALENESS_BUDGET,
         closure_refresh_frac: float = CLOSURE_REFRESH_FRAC,
+        mesh: Optional[jax.sharding.Mesh] = None,
     ):
         self.backend = resolve_query_backend(backend)
+        # Sketches whose counter rows are sharded over ``mesh``'s model
+        # axis answer the edge family shard-locally (a gather in each shard,
+        # one pmin); the other families read replicated registers or let
+        # the compiler partition them.
+        self.mesh = mesh
+        self.shards = 1 if mesh is None else mesh.shape["model"]
         self.pad_q = pad_q
         self.chunk_q = max(chunk_q, pad_q)
         self.closure_staleness_budget = closure_staleness_budget
@@ -197,6 +205,10 @@ class QueryEngine:
         if fn is None:
             jnp_fn, pallas_fn = _FAMILIES[family]
             impl = pallas_fn if self.backend == "pallas" else jnp_fn
+            if family == "edge" and self.mesh is not None:
+                from repro.core.distributed import distributed_edge_query
+
+                impl = functools.partial(distributed_edge_query, self.mesh)
             fn = jax.jit(_named(impl, f"glava_query_{family}"))
             self._jits[family] = fn
         return fn

@@ -111,6 +111,8 @@ class _TenantEngineView:
     :class:`~repro.api.planner.CompiledPlan` (and therefore subscriptions)
     runs against the fleet unchanged."""
 
+    mesh = None  # the fleet's stacks live on one device
+
     def __init__(self, session: "TenantSession"):
         self._session = session
 

@@ -206,7 +206,11 @@ def ingest_pallas(
     return pl.pallas_call(
         functools.partial(_ingest_kernel, steps=steps, n_tc=n_tc),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(counters.shape, jnp.float32),
+        # Inside shard_map the updated counters vary over the same mesh axes
+        # as the counters they alias.
+        out_shape=jax.ShapeDtypeStruct(
+            counters.shape, jnp.float32, vma=jax.typeof(counters).vma
+        ),
         input_output_aliases={6: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")

@@ -41,7 +41,7 @@ def run(config_name: str, batch: int, multi_pod: bool, outdir: Path):
         import dataclasses
 
         sk = dataclasses.replace(sketch, counters=counters)
-        out = distributed_ingest(mesh, sk, src, dst, w, stream_axes=dp)
+        out = distributed_ingest(mesh, sk, src, dst, w, stream_axes=dp, backend="onehot")
         return out.counters
 
     jf = jax.jit(

@@ -67,7 +67,10 @@ def test_mesh_phase_on_four_cpu_devices():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         sys.path.insert(0, ".")
         import chip_smoke
-        print(chip_smoke.phase_mesh("smoke", n_batches=2, batch=2048, n_nodes=3000))
+        for shape in ((2, 2), (1, 4)):
+            print(chip_smoke.phase_mesh(
+                "smoke", shape=shape, n_batches=2, batch=2048, n_nodes=3000
+            ))
         """
     )
     env = dict(os.environ, PYTHONPATH="src")
@@ -77,7 +80,8 @@ def test_mesh_phase_on_four_cpu_devices():
         capture_output=True, text=True, env=env, cwd=ROOT, timeout=600,
     )
     assert proc.returncode == 0, proc.stderr[-4000:]
-    assert "counters on 4 devices, 128 rows each; bit-identical" in proc.stdout
+    assert "counters on 4 devices, 128 rows each; bit-identical; 2 stream shards" in proc.stdout
+    assert "counters on 4 devices, 64 rows each; bit-identical; update: no collective" in proc.stdout
 
 
 def test_main_refuses_without_a_tpu(capsys):

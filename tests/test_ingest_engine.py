@@ -104,7 +104,7 @@ _DIST_SCRIPT = textwrap.dedent(
             sk, counters=jax.device_put(sk.counters, counter_sh)
         )
         args = [jax.device_put(a, stream_sh) for a in (src, dst, w)]
-        for backend in ("onehot", "scatter"):
+        for backend in ("onehot", "scatter", "pallas"):
             out = distributed_ingest(mesh, sk_sharded, *args, backend=backend)
             ref = sk.update(src, dst, w, backend="scatter")  # local oracle
             np.testing.assert_array_equal(

@@ -175,3 +175,73 @@ def test_dispatch_carries_the_ingest_kernels_grid_steps(traced, tmp_path):
     assert spans["glava.ingest.dispatch"] == {
         "kernel_steps": grid_steps(cfg.depth, cfg.width_rows, cfg.width_cols, slots)
     }
+
+
+_MESH_SCRIPT = """
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import numpy as np
+from jax.profiler import ProfileData
+import repro.api.stream as stream
+from repro.api import GraphStream, Query, QueryBatch, SketchConfig
+
+stream._bytes_limit = lambda device: 4 << 20  # 8 MB of counters: row-sharded
+out = sys.argv[1]
+rng = np.random.default_rng(0)
+keys = np.arange(300, dtype=np.uint32)
+with jax.profiler.trace(out):
+    gs = GraphStream(SketchConfig(depth=2, width_rows=1024, width_cols=1024), ingest_backend="pallas")
+    gs.subscribe(QueryBatch([Query.edge(keys, keys[::-1]), Query.in_flow(keys[:100]),
+                             Query.heavy(keys[:64], theta=0.01)]), every=1)
+    for _ in range(2):
+        gs.ingest(*rng.integers(0, 48, (2, 2048)).astype(np.uint32))
+(path,) = [os.path.join(d, f) for d, _, fs in os.walk(out) for f in fs if f.endswith(".xplane.pb")]
+events = []
+for plane in ProfileData.from_file(path).planes:
+    for line in plane.lines:
+        if any(e.name == "glava.ingest" for e in line.events):
+            events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)) for e in line.events]
+spans = [(n, s) for n, _, _, s in sorted(events, key=lambda e: e[1]) if n.startswith("glava.")]
+dispatch = [(a, b) for n, a, b, _ in events if n == "glava.ingest.dispatch"]
+calls = [(n, a, b) for n, a, b, _ in events
+         if n.startswith("PjitFunction(") and any(x <= a and b <= y for x, y in dispatch)]
+# Leave out the calls a program's first trace makes inside it.
+programs = sorted({n[len("PjitFunction("):-1] for n, a, b in calls
+                   if not any((x, y) != (a, b) and x <= a and b <= y for _, x, y in calls)})
+print("SPANS " + json.dumps(dict(spans=spans, programs=programs, shards=dict(gs._mesh.shape))))
+"""
+
+
+def test_mesh_session_spans_carry_its_shards(tmp_path):
+    """A row-sharded session (four placeholder devices) puts its placement
+    on ``glava.open.place``, the per-shard grid and the shard count on each
+    dispatch, and the shard count on each query family; its ingest runs one
+    program, ``_update_mesh``."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    from repro.kernels.ingest.kernel import grid_steps
+
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("XLA_FLAGS", None)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", _MESH_SCRIPT, str(tmp_path)], capture_output=True,
+                          text=True, env=env, cwd=root, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    got = json.loads([ln for ln in proc.stdout.splitlines() if ln.startswith("SPANS ")][-1][6:])
+    assert got["shards"] == {"data": 1, "model": 4}
+    by = {}
+    for name, stats in got["spans"]:
+        by.setdefault(name, []).append(stats)
+    assert by["glava.open.place"] == [{"bytes_per_device": 2 * 1024 * 1024 * 4 // 4}]
+    slots = [s["slots"] for s in by["glava.ingest.transfer"]]
+    assert by["glava.ingest.dispatch"] == [
+        {"kernel_steps": grid_steps(2, 256, 1024, n), "shards": 4} for n in slots
+    ]
+    assert by["glava.query.edge"] == [{"queries": 300, "padded": 512, "shards": 4}] * 2
+    assert by["glava.query.in_flow"] == [{"queries": 100, "padded": 256, "shards": 4}] * 2
+    assert by["glava.query.heavy"] == [{"queries": 64, "padded": 256, "shards": 4}] * 2
+    assert got["programs"] == ["_update_mesh"]
